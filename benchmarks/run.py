@@ -18,6 +18,10 @@ def main() -> None:
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
     quick = os.environ.get("BENCH_FULL", "0") != "1"
 
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     from benchmarks import (
         autotc_scaling,
         fig8_design_space,

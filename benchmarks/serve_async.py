@@ -35,6 +35,7 @@ from repro import runtime
 from repro.serve.async_frontend import AsyncCircuitServer
 from repro.serve.circuits import CircuitServer, TenantQoS
 from repro.serve.observability import TraceRecorder, export_chrome
+from repro.utils.compile_cache import use_compile_cache
 
 # deadline tiers cycled across tenants (seconds, scaled by --deadline-scale)
 TIERS = (
@@ -214,4 +215,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

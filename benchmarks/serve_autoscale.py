@@ -56,6 +56,7 @@ from repro.serve.autoscale import (
 from repro.serve.circuits import CircuitServer, TenantQoS
 from repro.serve.observability import TraceRecorder, export_chrome
 from repro.serve.planning import PlacementPolicy
+from repro.utils.compile_cache import use_compile_cache
 
 
 def make_extra(i: int, rng) -> ServableCircuit:
@@ -389,4 +390,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -39,6 +39,7 @@ from repro.core.genome import CircuitSpec, init_genome
 from repro.serve.circuits import CircuitRegistry, CircuitServer
 from repro.serve.observability import TraceRecorder, export_chrome
 from repro.serve.planning import PlacementPolicy
+from repro.utils.compile_cache import use_compile_cache
 
 # (features, bits/input, gates, classes) per tenant, cycled
 SHAPES = [(4, 2, 60, 2), (7, 4, 120, 3), (3, 2, 40, 4), (10, 4, 200, 5),
@@ -216,4 +217,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

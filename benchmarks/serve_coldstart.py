@@ -3,7 +3,7 @@
 Measures the two latencies the AOT serving artifacts exist to kill:
 
 1. **Cold boot** — a warm single-host fleet is exported with
-   `FleetRouter.export_fleet`, then two fresh subprocesses each bring a
+   `FleetRouter.export_fleet`, then two fresh processes each bring a
    host to *ready* (boot + first fused tick served) against the same
    circuits: one trace-from-scratch (`CircuitServer` over the stored
    registry, jit traces in the first tick's critical path) and one from
@@ -13,13 +13,13 @@ Measures the two latencies the AOT serving artifacts exist to kill:
    to both the scratch child and the warm exporter; the headline is
    ``boot_speedup = scratch_ready / artifact_ready``.
 
-2. **Pre-warmed swap** — in-process: serve to a steady p50 tick
-   latency, register a new tenant, `recompile` + `swap_plan` (prewarm
-   on, the default), and time the first post-swap tick.  The executable
-   for the changed shard was compiled *and invoked once* before the
-   generation fence, so the ratio of that first tick to where the new
-   (one-tenant-larger) plan settles stays near 1.  A second swap with
-   ``prewarm=False`` records the contrast.
+2. **Pre-warmed swap** — serve to a steady p50 tick latency, register
+   a new tenant, `recompile` + `swap_plan` (prewarm on, the default),
+   and time the first post-swap tick.  The executable for the changed
+   shard was compiled *and invoked once* before the generation fence,
+   so the ratio of that first tick to where the new (one-tenant-larger)
+   plan settles stays near 1.  A second swap with ``prewarm=False``
+   records the contrast.
 
 `check_bench.py` gates ``cold_traces_artifact == 0``, ``parity_ok``,
 ``boot_speedup >= CHECK_BENCH_MIN_BOOT_SPEEDUP`` (default 10) and
@@ -28,9 +28,12 @@ Measures the two latencies the AOT serving artifacts exist to kill:
     PYTHONPATH=src python benchmarks/serve_coldstart.py [--tenants N]
         [--rows N] [--steady-ticks N] [--backend pallas] [--keep PATH]
 
-The subprocess legs re-invoke this file with ``--child``; that mode is
-internal.  On CPU the ``pallas`` backend runs in interpret mode, so
-absolute times are plumbing numbers — the *ratios* are what transfer.
+Every leg (export, scratch boot, artifact boot, post-swap) runs in a
+child process that re-invokes this file with ``--child``, one after
+another; the parent runs no JAX computation, so on an accelerator each
+child can own the chip in turn.  On CPU the ``pallas`` backend runs in
+interpret mode, so absolute times are plumbing numbers — the *ratios*
+are what transfer.
 """
 from __future__ import annotations
 
@@ -54,6 +57,7 @@ from benchmarks.serve_circuits import make_fleet
 from repro.serve.artifacts import ArtifactStore
 from repro.serve.circuits import CircuitRegistry, CircuitServer
 from repro.serve.fleet import FleetRouter, InProcTransport, ServingHost
+from repro.utils.compile_cache import use_compile_cache
 
 PROBE_SEED = 7  # children and parent must agree on the probe traffic
 
@@ -97,8 +101,22 @@ def answers_digest(outs: dict) -> str:
 
 # ---------------------------------------------------------------- children
 
-def run_child(mode: str, artifact_dir: str, backend: str,
-              rows: int) -> None:
+def run_child(mode: str, artifact_dir: str, backend: str, rows: int,
+              n_tenants: int, steady_ticks: int, seed: int) -> None:
+    """Run one leg and report it as a JSON line on stdout."""
+    if mode == "export":
+        export, digest = export_warm_fleet(
+            artifact_dir, backend, n_tenants, rows, seed)
+        print(json.dumps({"export": export, "digest": digest}))
+    elif mode == "postswap":
+        print(json.dumps(measure_postswap(
+            artifact_dir, backend, rows, steady_ticks, seed)))
+    else:
+        boot_child(mode, artifact_dir, backend, rows)
+
+
+def boot_child(mode: str, artifact_dir: str, backend: str,
+               rows: int) -> None:
     """Bring one host to *ready* — boot + one fused tick served at
     every steady span bucket — and report timings + jit trace count as
     a JSON line on stdout."""
@@ -140,14 +158,15 @@ def run_child(mode: str, artifact_dir: str, backend: str,
     }))
 
 
-def spawn_child(mode: str, artifact_dir: str, backend: str,
-                rows: int) -> dict:
+def spawn_child(mode: str, artifact_dir: str, backend: str, rows: int,
+                n_tenants: int, steady_ticks: int, seed: int) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--child", mode,
          "--artifacts", artifact_dir, "--backend", backend,
-         "--rows", str(rows)],
+         "--rows", str(rows), "--tenants", str(n_tenants),
+         "--steady-ticks", str(steady_ticks), "--seed", str(seed)],
         capture_output=True, text=True, timeout=600, env=env, cwd=REPO,
     )
     if proc.returncode != 0:
@@ -157,7 +176,7 @@ def spawn_child(mode: str, artifact_dir: str, backend: str,
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
-# ------------------------------------------------------------------ parent
+# ------------------------------------------------------------ child legs
 
 def export_warm_fleet(artifact_dir: str, backend: str, n_tenants: int,
                       rows: int, seed: int) -> tuple:
@@ -231,6 +250,8 @@ def measure_postswap(artifact_dir: str, backend: str, rows: int,
     }
 
 
+# ------------------------------------------------------------------ parent
+
 def dir_bytes(path: str) -> int:
     return sum(os.path.getsize(os.path.join(r, f))
                for r, _, fs in os.walk(path) for f in fs)
@@ -240,18 +261,20 @@ def run(backend: str = "pallas", n_tenants: int = 6, rows: int = 8,
         steady_ticks: int = 30, seed: int = 0,
         keep: "str | None" = None) -> dict:
     artifact_dir = keep or tempfile.mkdtemp(prefix="coldstart_artifact_")
+    legs = {}
     try:
-        export, warm_digest = export_warm_fleet(
-            artifact_dir, backend, n_tenants, rows, seed)
-        scratch = spawn_child("scratch", artifact_dir, backend, rows)
-        artifact = spawn_child("artifact", artifact_dir, backend, rows)
-        post = measure_postswap(artifact_dir, backend, rows,
-                                steady_ticks, seed)
+        # one child at a time: each owns the device while it runs
+        for mode in ("export", "scratch", "artifact", "postswap"):
+            legs[mode] = spawn_child(mode, artifact_dir, backend, rows,
+                                     n_tenants, steady_ticks, seed)
         store_bytes = dir_bytes(artifact_dir)
     finally:
         if keep is None:
             shutil.rmtree(artifact_dir, ignore_errors=True)
 
+    export, warm_digest = legs["export"]["export"], legs["export"]["digest"]
+    scratch, artifact, post = (
+        legs["scratch"], legs["artifact"], legs["postswap"])
     rep = {
         "backend": backend,
         "n_tenants": n_tenants,
@@ -296,13 +319,14 @@ def main():
                     help="export the artifact here and keep it "
                          "(default: temp dir, removed)")
     ap.add_argument("--child", default=None,
-                    choices=["scratch", "artifact"],
+                    choices=["export", "scratch", "artifact", "postswap"],
                     help=argparse.SUPPRESS)  # internal subprocess mode
     ap.add_argument("--artifacts", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args()
 
     if args.child:
-        run_child(args.child, args.artifacts, args.backend, args.rows)
+        run_child(args.child, args.artifacts, args.backend, args.rows,
+                  args.tenants, args.steady_ticks, args.seed)
         return
 
     rep = run(backend=args.backend, n_tenants=args.tenants,
@@ -322,4 +346,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -64,6 +64,7 @@ from repro.serve.evolution import (
     refit_circuit,
 )
 from repro.serve.observability import TraceRecorder, export_chrome
+from repro.utils.compile_cache import use_compile_cache
 
 N_FEATS = 6
 TENANT = "t0"
@@ -361,4 +362,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -53,6 +53,7 @@ from repro.serve.fleet import (
     save_trace,
 )
 from repro.serve.observability import TraceRecorder, export_chrome
+from repro.utils.compile_cache import use_compile_cache
 
 
 def build_fleet(n_hosts: int, backend: str, tracer) -> FleetRouter:
@@ -294,4 +295,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -25,6 +25,7 @@ from repro.core.islands import (
 )
 from repro.data import load_dataset, train_test_split
 from repro.launch.mesh import make_host_mesh
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
@@ -73,4 +74,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

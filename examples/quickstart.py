@@ -12,6 +12,7 @@ from repro.core import hardware
 from repro.core.api import AutoTinyClassifier
 from repro.core.encoding import EncodingConfig
 from repro.data import load_dataset, train_test_split
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main(dataset: str = "blood"):
@@ -55,4 +56,5 @@ def main(dataset: str = "blood"):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main(sys.argv[1] if len(sys.argv) > 1 else "blood")

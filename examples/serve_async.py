@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.serve.async_frontend import AdmissionError, AsyncCircuitServer
 from repro.serve.circuits import CircuitServer, TenantQoS
+from repro.utils.compile_cache import use_compile_cache
 
 TIERS = {
     "tight": TenantQoS(max_batch=128, max_wait_s=0.01,
@@ -108,4 +109,5 @@ async def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     asyncio.run(main())

@@ -43,6 +43,7 @@ from repro.serve.observability import (
     prometheus_text,
 )
 from repro.serve.planning import PlacementPolicy
+from repro.utils.compile_cache import use_compile_cache
 
 # tenant name → dataset (heterogeneous widths and class counts)
 TENANTS = ("blood", "iris", "led", "wall-robot")
@@ -161,4 +162,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -36,6 +36,7 @@ from repro.serve.fleet import (
     generate,
 )
 from repro.serve.observability import prometheus_text
+from repro.utils.compile_cache import use_compile_cache
 
 N_TENANTS = 6
 N_EVENTS = 800
@@ -111,4 +112,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

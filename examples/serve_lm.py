@@ -13,6 +13,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.models import lm
 from repro.serve.engine import Engine, Request, throughput_report
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main(argv=None):
@@ -40,4 +41,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

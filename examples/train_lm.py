@@ -19,6 +19,7 @@ from repro.models.common import ModelConfig
 from repro.train import checkpoint as ckpt
 from repro.train.optimizer import OptConfig
 from repro.train.train_step import make_train_state, make_train_step
+from repro.utils.compile_cache import use_compile_cache
 
 # ~100M params: 12L × d512 × ff2048, vocab 8192 (wide-enough to be honest,
 # small enough for CPU steps)
@@ -65,4 +66,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

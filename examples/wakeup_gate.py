@@ -20,6 +20,7 @@ from repro.configs import get_config
 from repro.core.api import AutoTinyClassifier
 from repro.core.encoding import EncodingConfig
 from repro.models import lm
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
@@ -64,4 +65,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

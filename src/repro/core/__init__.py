@@ -1,18 +1,13 @@
 """Auto Tiny Classifiers — the paper's core contribution in JAX.
 
-Public surface:
-  * CircuitSpec / Genome            — genome.py
-  * EncodingConfig / fit_encoder    — encoding.py
-  * EvolveConfig / evolve           — evolve.py
-  * AutoTinyClassifier              — api.py (sklearn-style end-to-end flow)
+Modules:
+  * genome.py   — CircuitSpec / Genome / init_genome
+  * encoding.py — EncodingConfig / fit_encoder / pack_dataset
+  * evolve.py   — EvolveConfig / evolve / evolve_packed
+  * api.py      — AutoTinyClassifier (sklearn-style end-to-end flow)
+
+The package itself imports nothing: `repro.kernels` depends on
+`repro.core.gates`, and `evolve` depends on `repro.runtime`, which
+imports the kernels — so eager re-exports here would close an import
+cycle for any process whose first import is a kernel module.
 """
-from repro.core.genome import CircuitSpec, Genome, init_genome  # noqa: F401
-from repro.core.encoding import (  # noqa: F401
-    EncodingConfig,
-    PackedDataset,
-    fit_encoder,
-    encode,
-    pack_dataset,
-    split_masks,
-)
-from repro.core.evolve import EvolveConfig, EvolveState, evolve, evolve_packed  # noqa: F401

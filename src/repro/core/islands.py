@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.utils.jax_compat import shard_map
-
 from repro import runtime
 from repro.core import fitness as F
 from repro.core.encoding import PackedDataset
@@ -110,7 +108,7 @@ def evolve_islands(
     v_axes = P(icfg.data_axes)         # (W,) arrays
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             P(icfg.island_axis),        # keys

@@ -49,45 +49,56 @@ def _gate_select(op, a, b):
     return r
 
 
-def _kernel(
-    # scalar-prefetch (SMEM):
-    opcodes_ref,   # i32[P, n]
-    edge_src_ref,  # i32[P, n, 2]
-    out_src_ref,   # i32[P, O]
-    # VMEM blocks:
-    x_ref,         # u32[I, BW]
-    o_ref,         # u32[1, O, BW]
-    # scratch:
-    vals_ref,      # u32[I+n, BW]
-):
-    p = pl.program_id(0)
-    n_in = x_ref.shape[0]
-    n_nodes = opcodes_ref.shape[1]
-    n_out = out_src_ref.shape[1]
+def _walk_gates(p, n_in, n_nodes, n_out, opcodes_ref, edge_src_ref,
+                out_src_ref, vals_ref, o_ref):
+    """Forward sweep of circuit p over the seeded node-value table.
 
-    # Seed the node-value table with the input bits.
-    vals_ref[:n_in, :] = x_ref[...]
+    The genome operands are flat i32 vectors in SMEM: gate i of circuit p
+    is at ``p·n + i`` and its operands at ``(p·n + i)·2 + k``.  A 1-D
+    layout keeps their SMEM size at ``P·n`` words; a ``[P, n, 2]`` array
+    would be tiled to ``(⌈n/8⌉·8, 128)`` per circuit (155,648 B at
+    n = 300), which caps a launch at 6 circuits."""
+    base = p * n_nodes
 
     def body(i, _):
-        a_idx = edge_src_ref[p, i, 0]
-        b_idx = edge_src_ref[p, i, 1]
-        op = opcodes_ref[p, i]
-        a = vals_ref[a_idx, :]
-        b = vals_ref[b_idx, :]
-        vals_ref[n_in + i, :] = _gate_select(op, a, b)
+        g = base + i
+        a = vals_ref[edge_src_ref[2 * g], :]
+        b = vals_ref[edge_src_ref[2 * g + 1], :]
+        vals_ref[n_in + i, :] = _gate_select(opcodes_ref[g], a, b)
         return 0
 
     jax.lax.fori_loop(0, n_nodes, body, 0)
 
     for j in range(n_out):  # O is small and static — unrolled taps
-        o_ref[0, j, :] = vals_ref[out_src_ref[p, j], :]
+        o_ref[0, j, :] = vals_ref[out_src_ref[p * n_out + j], :]
+
+
+def _kernel(
+    # scalar-prefetch (SMEM), flattened:
+    opcodes_ref,   # i32[P·n]
+    edge_src_ref,  # i32[P·n·2]
+    out_src_ref,   # i32[P·O]
+    # VMEM blocks:
+    x_ref,         # u32[I, BW]
+    o_ref,         # u32[1, O, BW]
+    # scratch:
+    vals_ref,      # u32[I+n, BW]
+    *,
+    n_nodes: int,
+    n_out: int,
+):
+    n_in = x_ref.shape[0]
+    # Seed the node-value table with the input bits.
+    vals_ref[:n_in, :] = x_ref[...]
+    _walk_gates(pl.program_id(0), n_in, n_nodes, n_out, opcodes_ref,
+                edge_src_ref, out_src_ref, vals_ref, o_ref)
 
 
 def _spans_kernel(
-    # scalar-prefetch (SMEM):
-    opcodes_ref,   # i32[P, n]
-    edge_src_ref,  # i32[P, n, 2]
-    out_src_ref,   # i32[P, O]
+    # scalar-prefetch (SMEM), flattened:
+    opcodes_ref,   # i32[P·n]
+    edge_src_ref,  # i32[P·n·2]
+    out_src_ref,   # i32[P·O]
     block_off_ref,  # i32[P]  word-block offset of circuit p's span
     in_width_ref,   # i32[P]  live input rows of circuit p (rest masked to 0)
     # VMEM blocks:
@@ -95,6 +106,9 @@ def _spans_kernel(
     o_ref,         # u32[1, O, BW]
     # scratch:
     vals_ref,      # u32[I_max+n, BW]
+    *,
+    n_nodes: int,
+    n_out: int,
 ):
     """Span variant of `_kernel` for multi-tenant serving.
 
@@ -108,27 +122,12 @@ def _spans_kernel(
     """
     p = pl.program_id(0)
     n_in = x_ref.shape[0]
-    n_nodes = opcodes_ref.shape[1]
-    n_out = out_src_ref.shape[1]
-
     row = jax.lax.broadcasted_iota(jnp.int32, x_ref.shape, 0)
     vals_ref[:n_in, :] = jnp.where(
         row < in_width_ref[p], x_ref[...], jnp.uint32(0)
     )
-
-    def body(i, _):
-        a_idx = edge_src_ref[p, i, 0]
-        b_idx = edge_src_ref[p, i, 1]
-        op = opcodes_ref[p, i]
-        a = vals_ref[a_idx, :]
-        b = vals_ref[b_idx, :]
-        vals_ref[n_in + i, :] = _gate_select(op, a, b)
-        return 0
-
-    jax.lax.fori_loop(0, n_nodes, body, 0)
-
-    for j in range(n_out):
-        o_ref[0, j, :] = vals_ref[out_src_ref[p, j], :]
+    _walk_gates(p, n_in, n_nodes, n_out, opcodes_ref, edge_src_ref,
+                out_src_ref, vals_ref, o_ref)
 
 
 @functools.partial(
@@ -155,7 +154,7 @@ def eval_population_spans_kernel(
     block_off = word_off.astype(jnp.int32) // block_words
 
     return pl.pallas_call(
-        _spans_kernel,
+        functools.partial(_spans_kernel, n_nodes=n, n_out=n_out),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=grid,
@@ -172,8 +171,8 @@ def eval_population_spans_kernel(
         ),
         out_shape=jax.ShapeDtypeStruct((pop, n_out, span_words), jnp.uint32),
         interpret=interpret,
-    )(opcodes, edge_src, out_src, block_off, in_width.astype(jnp.int32),
-      x_words)
+    )(opcodes.reshape(-1), edge_src.reshape(-1), out_src.reshape(-1),
+      block_off, in_width.astype(jnp.int32), x_words)
 
 
 @functools.partial(
@@ -195,7 +194,7 @@ def eval_population_kernel(
     grid = (pop, w // block_words)
 
     return pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, n_nodes=n, n_out=n_out),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,
@@ -209,4 +208,5 @@ def eval_population_kernel(
         ),
         out_shape=jax.ShapeDtypeStruct((pop, n_out, w), jnp.uint32),
         interpret=interpret,
-    )(opcodes, edge_src, out_src, x_words)
+    )(opcodes.reshape(-1), edge_src.reshape(-1), out_src.reshape(-1),
+      x_words)

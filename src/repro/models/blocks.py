@@ -17,7 +17,6 @@ from repro.models.layers import dense_init, rms_norm
 from repro.models.rope import apply_mrope, apply_rope
 from repro.models.moe import moe_ffn, moe_ffn_sharded
 from repro.sharding.specs import constrain
-from repro.utils.jax_compat import shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -264,7 +263,7 @@ def _cache_write(cache, new_row, write_pos):
             spec_r = P(axes.fsdp, None, None, None)
 
             @partial(
-                shard_map, mesh=mesh,
+                jax.shard_map, mesh=mesh,
                 in_specs=(spec_c, spec_r, P()), out_specs=spec_c,
                 check_vma=False,
             )

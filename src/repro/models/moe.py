@@ -20,7 +20,6 @@ import jax.numpy as jnp
 
 from repro.models.common import MoEConfig
 from repro.sharding.specs import constrain
-from repro.utils.jax_compat import shard_map
 
 
 def moe_ffn(
@@ -157,7 +156,7 @@ def moe_ffn_sharded(
     e_loc = cfg.n_experts // mesh.shape[tp]
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(fsdp, None), P(None, None), P(tp, None, None),
                   P(tp, None, None), P(tp, None, None)),

@@ -2,8 +2,9 @@
 
 The Pallas backend owns the block/VMEM policy that used to live in
 `kernels/ops.py` (`pick_block_words`, the word-axis padding, the
-interpret-on-CPU auto-detection) — backend policy belongs to the
-backend, not to a module-level dispatcher.
+interpret-on-CPU auto-detection) and the SMEM bound on circuits per
+launch — backend policy belongs to the backend, not to a module-level
+dispatcher.
 """
 from __future__ import annotations
 
@@ -22,10 +23,14 @@ from repro.runtime.base import (
 )
 
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024  # leave headroom out of ~16 MB/core
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+# Scalar memory per TensorCore: the kernels' scalar-prefetched operands
+# (flattened genomes, span offsets, input widths) must fit in it.  The
+# reserve covers the compiler's padding of those operands and its own SMEM
+# use: compiled for v5e, the spans kernel at 300 gates and 3 outputs fits
+# 280 circuits and overflows at 287, and both kernels compile at the bound
+# this reserve gives (285).
+SMEM_BYTES = 1024 * 1024
+SMEM_RESERVE_BYTES = 16 * 1024
 
 
 @functools.partial(jax.jit, static_argnames=("span_words",))
@@ -74,8 +79,10 @@ class RefBackend(EvalBackend):
 class PallasBackend(EvalBackend):
     """Pallas TPU kernels (`kernels/circuit_eval.py`).
 
-    ``interpret=None`` auto-detects: interpret-mode off-TPU (bit-exact,
-    slow — plumbing validation on CPU containers), native on TPU.  Pass
+    ``interpret=None`` auto-detects: interpret mode when JAX's platform is
+    the CPU (bit-exact, slow — plumbing validation on CPU hosts), native
+    Mosaic kernels on any other platform, so an accelerator that fails to
+    come up fails loudly instead of serving from the interpreter.  Pass
     ``interpret=True/False`` to force either mode.
     """
 
@@ -85,7 +92,9 @@ class PallasBackend(EvalBackend):
         self.interpret = interpret
 
     def _interpret(self) -> bool:
-        return (not _on_tpu()) if self.interpret is None else self.interpret
+        if self.interpret is None:
+            return jax.default_backend() == "cpu"
+        return self.interpret
 
     def capabilities(self) -> BackendCapabilities:
         return BackendCapabilities(
@@ -98,6 +107,34 @@ class PallasBackend(EvalBackend):
             aot_format=aot.AOT_FORMAT,
             aot_format_version=aot.AOT_FORMAT_VERSION,
         )
+
+    def span_alignment(self, requested: int | None = None) -> int:
+        """Native kernels block the word axis in whole lanes, so an
+        explicit request is rounded up to a lane multiple there; the
+        interpreter honours it as given."""
+        align = super().span_alignment(requested)
+        if self._interpret():
+            return align
+        lane = circuit_eval.LANE
+        return -(-align // lane) * lane
+
+    def max_launch_slots(self, n_nodes: int, n_outputs: int) -> int:
+        """Most circuits one launch can hold: 4 bytes per opcode, two per
+        gate's operands, one per output tap, plus a span offset and an
+        input width, all within `SMEM_BYTES` less the reserve.  At 300
+        gates and 3 outputs that is 285 circuits."""
+        per_slot = 4 * (3 * int(n_nodes) + int(n_outputs) + 2)
+        return (SMEM_BYTES - SMEM_RESERVE_BYTES) // per_slot
+
+    def _check_slots(self, opcodes, out_src) -> None:
+        pop, n = opcodes.shape
+        limit = self.max_launch_slots(n, out_src.shape[1])
+        if pop > limit:
+            raise BackendCapabilityError(
+                f"{pop} circuits of {n} gates in one Pallas launch exceed "
+                f"its SMEM bound of {limit}; split the population over "
+                "several launches (more plan shards)"
+            )
 
     def pick_block_words(
         self, n_signals: int, w: int, lane: int = circuit_eval.LANE
@@ -112,6 +149,7 @@ class PallasBackend(EvalBackend):
         return min(block, w_padded)
 
     def eval_population(self, opcodes, edge_src, out_src, x_words):
+        self._check_slots(opcodes, out_src)
         n_in, w = x_words.shape
         n = opcodes.shape[1]
         block = self.pick_block_words(n_in + n, w)
@@ -132,6 +170,7 @@ class PallasBackend(EvalBackend):
         self, opcodes, edge_src, out_src, x_words, word_off, in_width,
         *, span_words: int,
     ):
+        self._check_slots(opcodes, out_src)
         n_in, w = x_words.shape
         n = opcodes.shape[1]
         block = self.pick_block_words(n_in + n, span_words)
@@ -172,7 +211,7 @@ class PallasGpuBackend(EvalBackend):
         "backend 'pallas-gpu' is a reserved slot: the GPU lowering of the "
         "circuit-eval kernels is not implemented yet (see ROADMAP.md). "
         "Use backend='ref' (any device) or backend='pallas' (TPU native, "
-        "interpret elsewhere)."
+        "interpret on CPU)."
     )
 
     def capabilities(self) -> BackendCapabilities:

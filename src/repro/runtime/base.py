@@ -173,6 +173,13 @@ class EvalBackend(abc.ABC):
             return max(int(self.capabilities().word_alignment), 1)
         return max(int(requested), 1)
 
+    def max_launch_slots(self, n_nodes: int, n_outputs: int) -> "int | None":
+        """Most circuits of this size one launch can hold, or None when
+        the backend has no such bound.  `PlanCompiler` refuses a shard
+        above it, so the limit surfaces when a plan is built, not as a
+        compiler error in the middle of a tick."""
+        return None
+
     def __repr__(self) -> str:
         return f"<{type(self).__name__} {self.name!r}>"
 
@@ -198,6 +205,9 @@ class _InstrumentedBackend(EvalBackend):
 
     def span_alignment(self, requested: int | None = None) -> int:
         return self._inner.span_alignment(requested)
+
+    def max_launch_slots(self, n_nodes: int, n_outputs: int) -> "int | None":
+        return self._inner.max_launch_slots(n_nodes, n_outputs)
 
     def compile_spans(self, spec, *, device=None):
         # compilation is a control-plane step, not a launch: delegate

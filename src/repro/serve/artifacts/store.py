@@ -256,13 +256,16 @@ class ArtifactStore:
     def put_executable(
         self, key: str, payload: bytes, *,
         backend: str, aot_format: str, aot_format_version: int,
-        spec: "tuple | list", device_kind: str = "",
+        spec: "tuple | list", platform: str = "", device_kind: str = "",
     ) -> str:
         """Store one serialized AOT executable under its cache key
         ``(backend, shard content hash, span bucket)`` (see
         `repro.runtime.aot.executable_key`).  ``spec`` is the
         `SpanLaunchSpec` shape tuple, kept so a booting host can
-        reconstruct launch buffers without recompiling anything."""
+        reconstruct launch buffers without recompiling anything;
+        ``platform`` and ``device_kind`` name the device it was compiled
+        for (``jax.Device.platform`` / ``.device_kind``) — a booting host
+        of another kind skips the entry."""
         if "/" in key or os.sep in key or key.startswith("."):
             raise ValueError(f"executable key {key!r} is not filesystem-safe")
         rel = os.path.join(OBJECTS_DIR, key + EXECUTABLE_SUFFIX)
@@ -275,6 +278,7 @@ class ArtifactStore:
             "format": aot_format,
             "format_version": int(aot_format_version),
             "spec": [int(v) for v in spec],
+            "platform": platform,
             "device_kind": device_kind,
         }
         self.flush()

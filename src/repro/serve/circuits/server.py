@@ -81,7 +81,10 @@ class CircuitServer:
     alignment (``PlacementPolicy(span_align=None)`` derives lane alignment
     from ``backend.capabilities().word_alignment`` — use on real TPUs).
     ``span_align`` is the legacy scalar knob, honoured when no policy is
-    passed.
+    passed.  ``device`` pins every launch of this server to one device —
+    how several one-chip replicas share a multi-chip host in one process;
+    without it shard *s* runs on local device ``s % n`` when the policy
+    shards, and on the default device otherwise.
     """
 
     def __init__(
@@ -93,6 +96,7 @@ class CircuitServer:
         span_align: int | None = None,
         stable_shapes: bool = True,
         tracer: TraceRecorder | None = None,
+        device: "jax.Device | None" = None,
     ):
         if policy is not None and span_align is not None:
             raise ValueError(
@@ -151,7 +155,9 @@ class CircuitServer:
         self._compiled: CompiledPlan | None = None
         self._dev: dict[str, tuple] = {}
         # shard s launches on device s % n (only when the policy shards
-        # and the host actually has multiple devices)
+        # and the host actually has multiple devices), or on the pinned
+        # device
+        self._device = device
         self._devices = self._shard_devices(policy)
         # -- AOT executables -------------------------------------------
         # compiled span launches keyed by (shard content hash, span
@@ -185,8 +191,9 @@ class CircuitServer:
         span per kernel-level eval call (no-op while tracing is off)."""
         return self.tracer.span(f"backend.{kind}", cat="kernel", **meta)
 
-    @staticmethod
-    def _shard_devices(policy: PlacementPolicy) -> "tuple | None":
+    def _shard_devices(self, policy: PlacementPolicy) -> "tuple | None":
+        if self._device is not None:
+            return (self._device,)
         if policy.n_shards > 1:
             mesh = specs.population_mesh(policy.n_shards)
             if mesh.devices.size > 1:
@@ -528,9 +535,11 @@ class CircuitServer:
         `ArtifactStore`: one serialized executable per shard × span
         bucket, keyed by ``(backend, shard content hash, span bucket)``.
         Executables are compiled for the default device (a booting host's
-        placement).  On a backend that declares no AOT support this
-        stores nothing and logs why — boot from such an artifact falls
-        back to trace-on-boot.  Returns the stored keys."""
+        placement), and each entry records that device's platform and
+        kind so a host of another kind skips it by name.  On a backend
+        that declares no AOT support this stores nothing and logs why —
+        boot from such an artifact falls back to trace-on-boot.  Returns
+        the stored keys."""
         caps = self.backend.capabilities()
         if not caps.supports_aot:
             _log.info(
@@ -548,6 +557,7 @@ class CircuitServer:
         use = sorted(
             {int(s) for s in (self._spans_seen if spans is None else spans)}
         ) or [self.span_bucket(1)]
+        target = jax.devices()[0]
         keys = []
         for shard in plan.shards:
             for span in use:
@@ -570,6 +580,8 @@ class CircuitServer:
                     aot_format=caps.aot_format,
                     aot_format_version=caps.aot_format_version,
                     spec=tuple(self._span_spec(shard, span)),
+                    platform=target.platform,
+                    device_kind=target.device_kind,
                 )
                 keys.append(kstr)
         return keys
@@ -578,15 +590,28 @@ class CircuitServer:
         """Boot-time half of `export_executables`: bind every stored
         executable that matches the current plan's shard hashes (and this
         backend/format) into the launch cache — **zero tracing** when the
-        artifact covers the plan.  Mismatched or broken entries fall back
-        to compiling, with the reason logged.  Returns the prewarm
-        summary."""
+        artifact covers the plan.  Entries built for another platform or
+        device kind are skipped by name (counted in ``skipped``); other
+        mismatched or broken entries fall back to compiling, with the
+        reason logged.  Returns the prewarm summary."""
         plan, _, _ = self._refresh_plan()
         caps = self.backend.capabilities()
+        here = jax.devices()[0]
         spans_by_hash: dict[str, set[int]] = {}
         prefix = f"{self.backend.name}--"
+        skipped = 0
         for kstr, entry in store.executable_entries().items():
             if entry.get("backend") != self.backend.name:
+                continue
+            built_for = (entry.get("platform", ""),
+                         entry.get("device_kind", ""))
+            if built_for != (here.platform, here.device_kind):
+                _log.warning(
+                    "stored executable %s was built for platform %r device "
+                    "%r; this host is %r %r — skipped (will compile)",
+                    kstr, *built_for, here.platform, here.device_kind,
+                )
+                skipped += 1
                 continue
             if (entry.get("format") != caps.aot_format
                     or int(entry.get("format_version", 0))
@@ -603,7 +628,7 @@ class CircuitServer:
             body, span_s = kstr[len(prefix):].rsplit("--s", 1)
             spans_by_hash.setdefault(body, set()).add(int(span_s))
         summary = {"loaded": 0, "compiled": 0, "trace_warmed": 0,
-                   "exec_warmed": 0, "load_failures": 0, "skipped": 0}
+                   "exec_warmed": 0, "load_failures": 0, "skipped": skipped}
         for shard in plan.shards:
             spans = sorted(spans_by_hash.get(shard.content_hash, ()))
             if not spans:
@@ -924,18 +949,9 @@ class CircuitServer:
                     live_dev = jax.device_put(live, device)
                     woff = jax.device_put(woff_host, device)
             self._spans_seen.add(span)
-            aot_fn = None
-            if self._aot_capable:
-                try:
-                    aot_fn = self._aot_executable(shard, span, device)
-                except Exception as err:  # noqa: BLE001 — AOT is an
-                    # optimization; any compile failure degrades to the
-                    # traced path rather than failing the tick
-                    _log.warning(
-                        "AOT compile failed for shard %d span %d (%s: %s); "
-                        "using traced launch", shard_idx, span,
-                        type(err).__name__, err,
-                    )
+            # a failed compile raises: the traced path would hit the same
+            # compiler, and a silent fallback would hide it
+            aot_fn = self._aot_executable(shard, span, device)
             t2 = perf()
             with tracer.span("tick.launch", cat="tick", shard=shard_idx,
                              span_words=span, slots=k_active):

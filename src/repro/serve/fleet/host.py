@@ -76,7 +76,10 @@ def dump_bundle(circuit: ServableCircuit, backend: str) -> bytes:
 
 
 class ServingHost:
-    """One serving process behind the transport seam."""
+    """One serving process behind the transport seam.
+
+    ``device`` pins the host's launches to one device, so several hosts
+    in one process can each own a chip of a multi-chip machine."""
 
     def __init__(
         self,
@@ -88,11 +91,13 @@ class ServingHost:
         tracer: "TraceRecorder | None" = None,
         clock: Callable[[], float] = time.monotonic,
         latency_est_s: float = 0.0,
+        device=None,
     ):
         self.host_id = host_id
         self.registry = registry
         self.server = CircuitServer(
-            registry, backend=backend, policy=policy, tracer=tracer
+            registry, backend=backend, policy=policy, tracer=tracer,
+            device=device,
         )
         self.frontend = AsyncCircuitServer(
             self.server, clock=clock, latency_est_s=latency_est_s

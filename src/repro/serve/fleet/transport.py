@@ -269,7 +269,11 @@ def spawn_host_process(
     The child prints its bound address as one JSON line; tenants arrive
     afterwards over the transport (``add_tenant`` bundles), exactly as
     in a migration — a process host is just a host whose every tenant
-    migrated in.  Returns (process, address)."""
+    migrated in.  Returns (process, address).
+
+    The child initialises JAX on its own.  On an accelerator the caller
+    must not hold the device: a process that has run any JAX computation
+    owns the chip until it exits, and the child then fails or hangs."""
     cfg = json.dumps(
         {"host_id": host_id, "backend": backend, "port": port}
     )

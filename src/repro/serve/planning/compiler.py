@@ -382,6 +382,14 @@ class PlanCompiler:
         i_max = max(c.spec.n_inputs for c in circuits)
         n_max = max(c.spec.n_nodes for c in circuits)
         o_max = max(c.spec.n_outputs for c in circuits)
+        limit = self.backend.max_launch_slots(n_max, o_max)
+        if limit is not None and len(circuits) > limit:
+            raise runtime.BackendCapabilityError(
+                f"shard {shard} holds {len(circuits)} circuits of up to "
+                f"{n_max} gates and {o_max} outputs, but one "
+                f"{self.backend.name!r} launch holds at most {limit} of "
+                "that size; raise PlacementPolicy.n_shards"
+            )
         padded = [pad_genome(c, i_max, n_max, o_max) for c in circuits]
 
         def frz(arr: np.ndarray) -> np.ndarray:

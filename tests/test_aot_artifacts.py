@@ -314,6 +314,78 @@ def test_missing_executable_file_falls_back_to_compile(tmp_path):
         np.testing.assert_array_equal(y, reg.get(t).predict(x))
 
 
+def test_executables_record_their_device(tmp_path):
+    reg = fleet(2)
+    server = CircuitServer(reg, backend="pallas")
+    _serve_all(server, reg)
+    store = ArtifactStore(str(tmp_path))
+    keys = server.export_executables(store)
+    here = jax.devices()[0]
+    for key in keys:
+        entry = store.executable_entries()[key]
+        assert entry["platform"] == here.platform
+        assert entry["device_kind"] == here.device_kind
+
+
+@pytest.mark.parametrize("built_for", [
+    {"platform": "tpu", "device_kind": "TPU v5 lite"},
+    {"platform": "", "device_kind": ""},  # entry with no device recorded
+], ids=["other-device", "unrecorded"])
+def test_executable_for_another_device_is_skipped_by_name(
+        tmp_path, caplog, built_for):
+    reg = fleet(2)
+    server = CircuitServer(reg, backend="pallas")
+    _serve_all(server, reg)
+    store = ArtifactStore(str(tmp_path))
+    store.put_registry(reg)
+    keys = server.export_executables(store)
+    manifest = tmp_path / "manifest.json"
+    m = json.loads(manifest.read_text())
+    for key in keys:
+        m["executables"][key].update(built_for)
+    manifest.write_text(json.dumps(m))
+    cold = CircuitServer(ArtifactStore(str(tmp_path)).load_registry(),
+                         backend="pallas")
+    with caplog.at_level("WARNING", logger="repro.serve.aot"):
+        summary = cold.preload_executables(ArtifactStore(str(tmp_path)))
+    assert summary["skipped"] == len(keys)
+    assert summary["loaded"] == 0 and summary["load_failures"] == 0
+    assert "was built for platform" in caplog.text
+    out = _serve_all(cold, reg)
+    for t, (x, y) in out.items():
+        np.testing.assert_array_equal(y, reg.get(t).predict(x))
+
+
+def test_failed_aot_compile_fails_the_tick():
+    from repro.runtime import PallasBackend
+
+    def refuse(spec, *, device=None):
+        raise RuntimeError("compiler refused the launch")
+
+    reg = fleet(2)
+    backend = PallasBackend()
+    backend.compile_spans = refuse
+    server = CircuitServer(reg, backend=backend)
+    x = np.zeros((3, reg.get("t0").encoder.n_features), np.float32)
+    server.submit("t0", x)
+    with pytest.raises(RuntimeError, match="compiler refused"):
+        server.tick()
+
+
+def test_server_pinned_to_a_device_launches_there():
+    reg = fleet(2)
+    device = jax.devices()[-1]
+    server = CircuitServer(reg, backend="pallas", device=device)
+    out = _serve_all(server, reg)
+    plan = server.plan()
+    for shard in plan.shards:
+        for arr in server._dev[shard.content_hash]:
+            assert arr.devices() == {device}
+    assert all(k[2] == device.id for k in server._aot)
+    for t, (x, y) in out.items():
+        np.testing.assert_array_equal(y, reg.get(t).predict(x))
+
+
 def test_ref_server_preload_trace_warms_instead(tmp_path):
     reg = fleet(2)
     ref_server = CircuitServer(reg, backend="ref")

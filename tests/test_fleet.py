@@ -8,6 +8,7 @@ assert the contract the subsystem exists for: a cross-host tenant move
 loses no request and changes no result.
 """
 import threading
+import time
 
 import jax
 import numpy as np
@@ -451,6 +452,15 @@ def test_router_live_submit_and_migration_buffering():
         worker.start()
         assert hold.wait(30.0)
         parked = router.submit(tenant, x, deadline_s=30.0)
+        # the submit dispatches on the router's pool: release the export
+        # only once the request is parked, or a loaded machine can run
+        # the whole migration before the pool thread looks
+        deadline = time.monotonic() + 30.0
+        while time.monotonic() < deadline:
+            with router._lock:
+                if router._migrating.get(tenant):
+                    break
+            time.sleep(0.001)
         release.set()
         worker.join(30.0)
         assert not worker.is_alive()

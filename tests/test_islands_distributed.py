@@ -17,7 +17,6 @@ from repro.core import encoding as E
 from repro.core.evolve import EvolveConfig, make_eval_fn
 from repro.core.islands import IslandConfig, evolve_islands, best_island, pad_words_for, _make_psum_eval_fn
 from repro.launch.mesh import make_host_mesh
-from repro.utils.jax_compat import shard_map
 from functools import partial
 
 mesh = make_host_mesh(data=2, model=4)
@@ -35,7 +34,7 @@ spec = CircuitSpec(bits.shape[1], 50, 1, gates.FULL_FS)
 # exactness: psum-sharded fitness == single-device fitness
 g = jax.vmap(lambda k: init_genome(k, spec))(jax.random.split(jax.random.key(5), 3))
 ft_ref, fv_ref = make_eval_fn(spec, data, mtr, mva)(g)
-@partial(shard_map, mesh=mesh,
+@partial(jax.shard_map, mesh=mesh,
          in_specs=(P(), P(None,"data"), P(None,"data"), P(None,"data"),
                    P("data"), P("data"), P("data")),
          out_specs=P(), check_vma=False)
@@ -152,10 +151,9 @@ from functools import partial
 from jax.sharding import PartitionSpec as P
 from repro.train.grad_compress import quantize_with_feedback
 from repro.launch.mesh import make_host_mesh
-from repro.utils.jax_compat import shard_map
 mesh = make_host_mesh(data=4)
 g = jnp.arange(32, dtype=jnp.float32).reshape(4, 8) / 7.3
-@partial(shard_map, mesh=mesh, in_specs=P("data", None), out_specs=P("data", None),
+@partial(jax.shard_map, mesh=mesh, in_specs=P("data", None), out_specs=P("data", None),
          check_vma=False)
 def compressed_allreduce(g_loc):
     scale = jax.lax.pmax(jnp.max(jnp.abs(g_loc)), "data") / 127.0

@@ -57,6 +57,46 @@ def test_span_align_resolution_against_backend():
     assert explicit.span_align % pal.capabilities().word_alignment == 0
 
 
+def test_native_pallas_rounds_span_alignment_to_lanes():
+    from repro.runtime import PallasBackend
+
+    native = PallasBackend(interpret=False)
+    assert native.span_alignment(1) == 128
+    assert native.span_alignment(200) == 256
+    assert PallasBackend(interpret=True).span_alignment(1) == 1
+
+
+def test_launch_slot_bound_is_named_by_the_compiler(registry):
+    """A shard over the backend's per-launch slot bound is refused when
+    the plan is built, naming the bound; more shards fit."""
+    from repro.runtime import BackendCapabilityError, PallasBackend
+
+    assert PallasBackend().max_launch_slots(300, 3) == 285
+    assert get_backend("ref").max_launch_slots(300, 3) is None
+    small = PallasBackend()
+    small.max_launch_slots = lambda n_nodes, n_outputs: 2
+    n = len(registry)
+    with pytest.raises(BackendCapabilityError, match="at most 2"):
+        PlanCompiler(small, PlacementPolicy()).compile(registry.catalog())
+    plan = PlanCompiler(small, PlacementPolicy(n_shards=-(-n // 2))).compile(
+        registry.catalog())
+    assert max(sh.n_slots for sh in plan.shards) <= 2
+
+
+def test_pallas_launch_over_slot_bound_raises():
+    import jax.numpy as jnp
+
+    from repro.runtime import BackendCapabilityError, PallasBackend
+
+    small = PallasBackend()
+    small.max_launch_slots = lambda n_nodes, n_outputs: 2
+    opc = jnp.zeros((3, 4), jnp.int32)
+    with pytest.raises(BackendCapabilityError, match="SMEM bound of 2"):
+        small.eval_population(opc, jnp.zeros((3, 4, 2), jnp.int32),
+                              jnp.zeros((3, 1), jnp.int32),
+                              jnp.zeros((2, 4), jnp.uint32))
+
+
 # ---------------------------------------------------------------------------
 # Compilation: determinism, assignment, goldens
 # ---------------------------------------------------------------------------
