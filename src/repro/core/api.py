@@ -28,6 +28,7 @@ from repro.core import fitness as F
 from repro.core import gates, hardware, netlist, verilog
 from repro.core.evolve import EvolveConfig, EvolveState, evolve_packed
 from repro.core.genome import CircuitSpec, Genome, opcodes
+from repro.observability.trace import NULL_TRACER
 
 # On-disk ServableCircuit bundle format (see ServableCircuit.save):
 # a single .npz holding the genome/encoder arrays plus a JSON metadata
@@ -355,6 +356,12 @@ class AutoTinyClassifier:
 
     # ------------------------------------------------------------------
     def fit(self, x: np.ndarray, y: np.ndarray, n_classes: int | None = None):
+        # spans cost one branch unless a JAX profiler capture records
+        # (repro.observability.trace)
+        with NULL_TRACER.span("fit"):
+            return self._fit(x, y, n_classes)
+
+    def _fit(self, x: np.ndarray, y: np.ndarray, n_classes: int | None):
         x = np.asarray(x, np.float32)
         y = np.asarray(y, np.int64)
         self.n_classes_ = n_classes or int(y.max()) + 1
@@ -364,25 +371,27 @@ class AutoTinyClassifier:
         best = None
         self.records_ = []
         for ei, ecfg in enumerate(self.encodings):
-            enc = E.fit_encoder(x, ecfg)
-            bits = E.encode(enc, x)
-            data = E.pack_dataset(bits, y, self.n_classes_, n_out)
-            w = data.x_words.shape[1]
-            mtr, mva = E.split_masks(
-                x.shape[0], w, self.val_fraction, seed=self.seed + ei
-            )
+            with NULL_TRACER.span("fit.encode"):
+                enc = E.fit_encoder(x, ecfg)
+                bits = E.encode(enc, x)
+                data = E.pack_dataset(bits, y, self.n_classes_, n_out)
+                w = data.x_words.shape[1]
+                mtr, mva = E.split_masks(
+                    x.shape[0], w, self.val_fraction, seed=self.seed + ei
+                )
             spec = CircuitSpec(
                 n_inputs=bits.shape[1], n_nodes=self.n_gates,
                 n_outputs=n_out, fn_set=self.fn_set,
             )
             key = jax.random.key(self.seed * 1000 + ei)
             final: EvolveState = evolve_packed(key, spec, self.cfg, data, mtr, mva)
-            rec = FitRecord(
-                encoding=ecfg,
-                val_fitness=float(final.best_val),
-                train_fitness=float(final.best_train),
-                generations=int(final.gen),
-            )
+            with NULL_TRACER.span("fit.readback"):  # waits for the loop
+                rec = FitRecord(
+                    encoding=ecfg,
+                    val_fitness=float(final.best_val),
+                    train_fitness=float(final.best_train),
+                    generations=int(final.gen),
+                )
             self.records_.append(rec)
             if best is None or rec.val_fitness > best[0]:
                 # per-bit activation frequency of the encoded training

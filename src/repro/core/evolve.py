@@ -30,6 +30,7 @@ from repro.core import fitness as F
 from repro.core.encoding import PackedDataset
 from repro.core.genome import CircuitSpec, Genome, init_genome, opcodes
 from repro.core.mutate import mutate_children
+from repro.observability.trace import NULL_TRACER
 
 # Batched eval: stacked genomes (leading λ axis) → (train_fits, val_fits).
 BatchEvalFn = Callable[[Genome], tuple[jax.Array, jax.Array]]
@@ -159,13 +160,22 @@ def evolve(
     key: jax.Array, spec: CircuitSpec, cfg: EvolveConfig, eval_fn: BatchEvalFn,
     seed_genome: "Genome | None" = None,
 ) -> EvolveState:
-    """Run to termination (lax.while_loop — early exit, no history)."""
-    state = init_state(key, spec, eval_fn, seed_genome=seed_genome)
-    return jax.lax.while_loop(
-        lambda s: not_terminated(s, cfg),
-        lambda s: generation_step(s, spec, cfg, eval_fn),
-        state,
-    )
+    """Run to termination (lax.while_loop — early exit, no history).
+
+    Spans ``evolve.init`` (the eager initial state) and ``evolve.loop``
+    (tracing, lowering, compile or cache load, and enqueue of the loop),
+    and the count ``evolve.loop_traces`` (once per trace of the body),
+    reach a JAX profiler capture (repro.observability.trace)."""
+
+    def body(s):
+        NULL_TRACER.count("evolve.loop_traces")
+        return generation_step(s, spec, cfg, eval_fn)
+
+    with NULL_TRACER.span("evolve.init"):
+        state = init_state(key, spec, eval_fn, seed_genome=seed_genome)
+    with NULL_TRACER.span("evolve.loop"):
+        return jax.lax.while_loop(
+            lambda s: not_terminated(s, cfg), body, state)
 
 
 def evolve_with_history(

@@ -1,20 +1,33 @@
 """TraceRecorder: a bounded, fake-clock-testable event timeline.
 
-The measurement core of the serving stack's observability layer.  One
-recorder holds one timeline: every layer that participates (the
-micro-batching server's tick phases, the async front-end's request
-lifecycle, the deadline scheduler's fires, the autoscale controller's
-swaps, the execution backend's kernel launches) appends typed events —
-span begin/end, instants, counters, and cross-thread async spans — into
-one bounded ring buffer, so a single export shows *where a request's
-time went* across every layer at once.
+The measurement core of the program's observability.  One recorder holds
+one timeline: every layer that participates (the micro-batching server's
+tick phases, the async front-end's request lifecycle, the deadline
+scheduler's fires, the autoscale controller's swaps, the execution
+backend's kernel launches) appends typed events — span begin/end,
+instants, counters, and cross-thread async spans — into one bounded ring
+buffer, so a single export shows *where a request's time went* across
+every layer at once.
+
+The search (`AutoTinyClassifier.fit`, `evolve`) records through the
+shared `NULL_TRACER`: its spans and counter cost one branch each until a
+JAX profiler capture is recording.  While one is, every scoped `span()`
+— on any recorder, enabled or not — is also a
+`jax.profiler.TraceAnnotation` on the profiler's host plane, on the same
+clock as the device's operations, and adds its count and seconds to a
+process-wide capture table (`captured()`); `count()` adds to the same
+table.  Only the scoped `span()` is mirrored: `begin`/`end` and async
+spans may cross functions and threads, which a profiler annotation
+cannot.
 
 Design constraints, in order:
 
   * **Zero-cost when disabled.**  Production servers construct against
     the shared `NULL_TRACER`; every record method is one attribute load
     and one branch, and `span()` returns a single shared no-op context
-    manager — no event object, no deque append, no per-call allocation.
+    manager — no event object, no deque append, no per-call allocation —
+    while no profiler capture is recording (one more call: the
+    profiler's own `TraceMe.is_enabled` gate).
     The serving benchmarks measure this (``trace_overhead_pct`` in
     BENCH_serve.json) and `check_bench.py` gates it.
   * **Bounded.**  Events live in a ``deque(maxlen=capacity)`` ring: a
@@ -49,6 +62,36 @@ import threading
 import time
 from typing import Callable, NamedTuple
 
+from jax._src.lib import _profiler
+from jax.profiler import TraceAnnotation
+
+# the profiler's own gate: true while a capture would record an annotation
+_capturing = _profiler.TraceMe.is_enabled
+
+# name -> [count, seconds] over every capture since `reset_captured`
+_captured: dict = {}
+_captured_lock = threading.Lock()
+
+
+def _add_captured(name: str, n: int, seconds: float) -> None:
+    with _captured_lock:
+        entry = _captured.setdefault(name, [0, 0.0])
+        entry[0] += n
+        entry[1] += seconds
+
+
+def captured() -> dict:
+    """``{name: {"count", "seconds"}}``: the spans and counts recorded
+    while a profiler capture was on, since the last `reset_captured`."""
+    with _captured_lock:
+        return {name: {"count": n, "seconds": sec}
+                for name, (n, sec) in _captured.items()}
+
+
+def reset_captured() -> None:
+    with _captured_lock:
+        _captured.clear()
+
 
 class TraceEvent(NamedTuple):
     """One timeline event (timestamps in the recorder's clock domain)."""
@@ -78,25 +121,36 @@ _NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    """Context manager emitting a matched B/E pair on one track."""
+    """Context manager emitting a matched B/E pair on one track and,
+    while a profiler capture records, a profiler annotation and a row of
+    the capture table."""
 
-    __slots__ = ("_rec", "_name", "_cat", "_track", "_args")
+    __slots__ = ("_rec", "_name", "_cat", "_track", "_args", "_annotation",
+                 "_t0")
 
-    def __init__(self, rec, name, cat, track, args):
+    def __init__(self, rec, name, cat, track, args, capture):
         self._rec = rec
         self._name = name
         self._cat = cat
         self._track = track
         self._args = args
+        self._annotation = TraceAnnotation(name) if capture else None
 
     def __enter__(self) -> "_Span":
         self._rec.begin(
             self._name, cat=self._cat, track=self._track,
             **(self._args or {}),
         )
+        if self._annotation is not None:
+            self._annotation.__enter__()
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
+        if self._annotation is not None:
+            seconds = time.perf_counter() - self._t0
+            self._annotation.__exit__(*exc)
+            _add_captured(self._name, 1, seconds)
         self._rec.end(self._name, cat=self._cat, track=self._track)
         return False
 
@@ -125,6 +179,8 @@ class TraceRecorder:
         )
         self._recorded = 0
         self._ids = itertools.count(1)
+        self._totals: dict = {}   # running totals of `count`
+        self._totals_lock = threading.Lock()
 
     # -- lifecycle ------------------------------------------------------
     def enable(self) -> "TraceRecorder":
@@ -183,11 +239,14 @@ class TraceRecorder:
     def span(self, name: str, *, cat: str = "", track: "str | None" = None,
              **args):
         """``with tracer.span("tick.encode", tenant=t): ...`` — emits a
-        matched B/E pair.  Disabled recorders return one shared no-op
-        context manager: no allocation on the hot path."""
-        if not self.enabled:
+        matched B/E pair.  While a profiler capture records, the span is
+        also a profiler annotation of ``name`` and adds to `captured()`,
+        on a disabled recorder too.  Otherwise a disabled recorder returns
+        one shared no-op context manager: no allocation on the hot path."""
+        capture = _capturing()
+        if not (self.enabled or capture):
             return _NOOP_SPAN
-        return _Span(self, name, cat, track, args)
+        return _Span(self, name, cat, track, args, capture)
 
     def instant(self, name: str, *, cat: str = "",
                 track: "str | None" = None, **args) -> None:
@@ -198,6 +257,17 @@ class TraceRecorder:
                 track: "str | None" = None) -> None:
         """One sample of a named counter series (queue depth, ...)."""
         self._record("C", name, cat, track, {"value": value})
+
+    def count(self, name: str, n: int = 1) -> None:
+        """Add ``n`` to a named count: to `captured()` while a profiler
+        capture records, and as a counter sample of the recorder's running
+        total when enabled."""
+        if _capturing():
+            _add_captured(name, n, 0.0)
+        if self.enabled:
+            with self._totals_lock:
+                total = self._totals[name] = self._totals.get(name, 0) + n
+            self._record("C", name, "", None, {"value": total})
 
     # -- async (cross-thread) spans ------------------------------------
     def async_begin(self, name: str, id: int, *, cat: str = "request",
@@ -234,8 +304,9 @@ class TraceRecorder:
                 f"/{self.capacity} events, {self.dropped} dropped>")
 
 
-#: Shared disabled recorder — what every serving layer defaults to.
-#: Recording through it is a single branch; `span()` through it is a
-#: single shared no-op object.  Never enable this instance (it is shared
+#: Shared disabled recorder — what every serving layer defaults to, and
+#: what the search records through.  Recording through it is a single
+#: branch; `span()` through it is a single shared no-op object outside a
+#: profiler capture.  Never enable this instance (it is shared
 #: process-wide); construct a fresh `TraceRecorder` to actually trace.
 NULL_TRACER = TraceRecorder(capacity=1, enabled=False)

@@ -36,6 +36,7 @@ import numpy as np
 
 from repro import runtime
 from repro.core import encoding as E
+from repro.observability.trace import NULL_TRACER, TraceRecorder
 from repro.runtime import aot as runtime_aot
 from repro.core.api import decode_predictions
 from repro.serve.circuits.metrics import (
@@ -45,7 +46,6 @@ from repro.serve.circuits.metrics import (
     TickReport,
 )
 from repro.serve.circuits.registry import CircuitRegistry
-from repro.serve.observability.trace import NULL_TRACER, TraceRecorder
 from repro.serve.planning import (
     CompiledPlan,
     PlacementPolicy,

@@ -36,6 +36,7 @@ from typing import Callable
 import numpy as np
 
 from repro.core import encoding as E
+from repro.observability.trace import NULL_TRACER
 from repro.serve.evolution.drift import DriftConfig, DriftDetector
 from repro.serve.evolution.promote import (
     PromotionPolicy,
@@ -48,7 +49,6 @@ from repro.serve.evolution.refit import (
     RefitWorker,
     ReplayBuffer,
 )
-from repro.serve.observability.trace import NULL_TRACER
 
 
 class EvolutionManager:

@@ -35,9 +35,9 @@ from typing import Callable
 import numpy as np
 
 from repro.core.api import ServableCircuit
+from repro.observability.trace import NULL_TRACER, TraceRecorder
 from repro.serve.circuits.registry import CircuitRegistry
 from repro.serve.circuits.server import CircuitServer, StalePlanError
-from repro.serve.observability.trace import NULL_TRACER, TraceRecorder
 from repro.serve.planning import circuit_digest
 
 _SWAP_RETRIES = 8

@@ -40,8 +40,8 @@ import numpy as np
 from repro.core import encoding as E
 from repro.core.api import ServableCircuit
 from repro.core.evolve import EvolveConfig, evolve_packed
+from repro.observability.trace import NULL_TRACER, TraceRecorder
 from repro.serve.evolution.drift import bit_activation_stats
-from repro.serve.observability.trace import NULL_TRACER, TraceRecorder
 
 
 @dataclasses.dataclass(frozen=True)

@@ -36,12 +36,12 @@ from typing import Callable
 import numpy as np
 
 from repro.core.api import ServableCircuit, load_servable, save_servable
+from repro.observability.trace import TraceRecorder
 from repro.serve.async_frontend.frontend import AsyncCircuitServer
 from repro.serve.circuits.metrics import FrontendStats
 from repro.serve.circuits.registry import CircuitRegistry, TenantQoS
 from repro.serve.circuits.server import CircuitServer, StalePlanError
 from repro.serve.fleet.artifact import FleetArtifact, HostConfig
-from repro.serve.observability.trace import TraceRecorder
 from repro.serve.planning import PlacementPolicy
 
 _SWAP_RETRIES = 8

@@ -44,13 +44,13 @@ from typing import Callable, Mapping, Sequence
 
 import numpy as np
 
+from repro.observability.trace import NULL_TRACER, TraceRecorder
 from repro.serve.autoscale.controller import CounterWindow
 from repro.serve.circuits.registry import CircuitRegistry
 from repro.serve.fleet.host import dump_bundle
 from repro.serve.fleet.plan import FleetPlan, FleetPlanner, _plan_hash
 from repro.serve.fleet.transport import Transport, _ERROR_TYPES
 from repro.serve.fleet.workload import WorkloadEvent, chunked
-from repro.serve.observability.trace import NULL_TRACER, TraceRecorder
 
 _ROUTE_RETRIES = 5
 

@@ -2,8 +2,8 @@
 
 The serving stack's aggregate stats (`ServerStats`/`FrontendStats`) say
 *how fast*; this package says *where the time went*.  A `TraceRecorder`
-(bounded ring buffer, injected clock, zero-cost when disabled) collects
-one timeline across every layer:
+(`repro.observability.trace`: bounded ring buffer, injected clock,
+zero-cost when disabled) collects one timeline across every layer:
 
   * request-lifecycle async spans from the front-end — submit → queue
     wait → scheduler fire (with trigger reason) → launch → resolve,
@@ -30,7 +30,7 @@ from repro.serve.observability.export import (
     prometheus_text,
     to_chrome,
 )
-from repro.serve.observability.trace import (
+from repro.observability.trace import (
     NULL_TRACER,
     TraceEvent,
     TraceRecorder,

@@ -26,7 +26,7 @@ import json
 import os
 from typing import Iterable
 
-from repro.serve.observability.trace import TraceEvent, TraceRecorder
+from repro.observability.trace import TraceEvent, TraceRecorder
 
 _PID = 1  # one serving process per trace
 

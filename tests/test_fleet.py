@@ -18,6 +18,7 @@ from repro.core import encoding as E
 from repro.core import gates
 from repro.core.api import ServableCircuit
 from repro.core.genome import CircuitSpec, init_genome
+from repro.observability.trace import TraceRecorder
 from repro.serve.circuits import CircuitRegistry
 from repro.serve.fleet import (
     FleetPlanner,
@@ -36,7 +37,6 @@ from repro.serve.fleet import (
 )
 from repro.serve.fleet.transport import encode_frame, _dec, _enc
 from repro.serve.fleet.workload import chunked
-from repro.serve.observability.trace import TraceRecorder
 
 RNG = np.random.RandomState(0)
 

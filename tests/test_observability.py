@@ -1,9 +1,12 @@
-"""End-to-end tracing & telemetry (`repro.serve.observability`).
+"""End-to-end tracing & telemetry (`repro.observability`,
+`repro.serve.observability`).
 
-Three layers of coverage:
+Four layers of coverage:
 
   * `TraceRecorder` in isolation — fake clock, ring bounding, the
     zero-allocation disabled path.
+  * The profiler mirror — spans on the JAX profiler's host plane and in
+    the capture table while a capture records, and nothing outside one.
   * The Chrome-trace exporter's schema invariants — matched B/E pairs,
     proper per-thread nesting, monotonic timestamps, async id matching —
     including on deliberately corrupted windows (evicted opens/closes).
@@ -13,8 +16,10 @@ Three layers of coverage:
     — and the phase/QPS telemetry (`phase_breakdown`, window QPS,
     Prometheus snapshot) riding the same run.
 """
+import glob
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -37,7 +42,7 @@ from repro.serve.observability import (
     prometheus_text,
     to_chrome,
 )
-from repro.serve.observability.trace import _NOOP_SPAN
+from repro.observability.trace import _NOOP_SPAN, captured, reset_captured
 from repro.serve.planning import PlacementPolicy
 from tests.test_serve_circuits import TENANT_SHAPES, make_servable
 
@@ -164,6 +169,108 @@ def test_recorder_enable_disable_toggles_live():
     tr.enable()
     tr.instant("kept")
     assert [e.name for e in tr.events()] == ["kept"]
+
+
+# ---------------------------------------------------------------------------
+# Profiler mirror and capture table
+# ---------------------------------------------------------------------------
+
+def host_event_names(trace_dir) -> set:
+    """Names of the events on the host planes of a profiler capture."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(str(trace_dir / "**" / "*.xplane.pb"), recursive=True)
+    return {ev.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host")
+            for line in plane.lines for ev in line.events}
+
+
+def test_disabled_span_outside_a_capture_is_the_noop_and_records_nothing():
+    reset_captured()
+    tr = TraceRecorder(clock=FakeClock(), enabled=False)
+    with tr.span("outside") as sp:
+        assert sp is _NOOP_SPAN
+    assert NULL_TRACER.span("outside") is _NOOP_SPAN
+    assert captured() == {}
+
+
+def test_disabled_span_under_a_capture_reaches_the_profiler_and_the_table(
+        tmp_path):
+    reset_captured()
+    tr = TraceRecorder(clock=FakeClock(), enabled=False)
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(2):
+            with tr.span("mirrored.span") as sp:
+                assert sp is not _NOOP_SPAN
+                jax.numpy.ones(4).block_until_ready()
+        with NULL_TRACER.span("mirrored.null"):
+            pass
+    assert len(tr) == 0  # the ring of a disabled recorder stays empty
+    got = captured()
+    assert got["mirrored.span"]["count"] == 2
+    assert got["mirrored.span"]["seconds"] > 0
+    assert got["mirrored.null"]["count"] == 1
+    assert {"mirrored.span", "mirrored.null"} <= host_event_names(tmp_path)
+    # after the capture a span is the no-op again and adds nothing
+    assert tr.span("after") is _NOOP_SPAN
+    assert set(captured()) == {"mirrored.span", "mirrored.null"}
+
+
+def test_count_adds_only_under_a_capture_and_samples_an_enabled_ring(
+        tmp_path):
+    reset_captured()
+    tr = TraceRecorder(clock=FakeClock())
+    tr.count("things")
+    NULL_TRACER.count("things", 5)
+    assert captured() == {}
+    # an enabled recorder samples its own running total either way
+    assert [(e.phase, e.args) for e in tr.events()] == [
+        ("C", {"value": 1})]
+    with jax.profiler.trace(str(tmp_path)):
+        tr.count("things", 2)
+        NULL_TRACER.count("things")
+    assert captured() == {"things": {"count": 3, "seconds": 0.0}}
+    assert tr.events()[-1].args == {"value": 3}
+    reset_captured()
+    assert captured() == {}
+
+
+def test_capture_table_keeps_every_update_from_many_threads(tmp_path):
+    """Spans and counts from more threads than cores, with the switch
+    interval shortened: a lost update would leave a total short."""
+    import os
+    import sys
+    import threading
+
+    threads, rounds = 2 * (os.cpu_count() or 4), 200
+    tr = TraceRecorder(capacity=16)
+
+    def work():
+        for _ in range(rounds):
+            NULL_TRACER.count("stress.count")
+            tr.count("stress.ring")
+            with NULL_TRACER.span("stress.span"):
+                pass
+
+    reset_captured()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            workers = [threading.Thread(target=work) for _ in range(threads)]
+            for w in workers:
+                w.start()
+            for w in workers:
+                w.join(timeout=60)
+            assert not any(w.is_alive() for w in workers)
+    finally:
+        sys.setswitchinterval(interval)
+    got = captured()
+    reset_captured()
+    assert got["stress.count"]["count"] == threads * rounds
+    assert got["stress.ring"]["count"] == threads * rounds
+    assert got["stress.span"]["count"] == threads * rounds
+    assert tr._totals == {"stress.ring": threads * rounds}
 
 
 # ---------------------------------------------------------------------------
