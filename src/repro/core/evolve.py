@@ -20,6 +20,9 @@ kernel, and the shard_map'd distributed islands (repro.core.islands).
 from __future__ import annotations
 
 import dataclasses
+import functools
+import sys
+import types
 from typing import Callable, NamedTuple
 
 import jax
@@ -27,10 +30,15 @@ import jax.numpy as jnp
 
 from repro import runtime
 from repro.core import fitness as F
+from repro.core import genome as genome_module
+from repro.core import mutate as mutate_module
 from repro.core.encoding import PackedDataset
 from repro.core.genome import CircuitSpec, Genome, init_genome, opcodes
 from repro.core.mutate import mutate_children
+from repro.kernels import circuit_eval
+from repro.kernels import ref as ref_kernels
 from repro.observability.trace import NULL_TRACER
+from repro.runtime import backends as runtime_backends
 
 # Batched eval: stacked genomes (leading λ axis) → (train_fits, val_fits).
 BatchEvalFn = Callable[[Genome], tuple[jax.Array, jax.Array]]
@@ -156,26 +164,34 @@ def not_terminated(state: EvolveState, cfg: EvolveConfig) -> jax.Array:
     return (state.gen < cfg.max_gens) & (state.since < cfg.kappa)
 
 
+def _run_loop(
+    state: EvolveState, spec: CircuitSpec, cfg: EvolveConfig,
+    eval_fn: BatchEvalFn,
+) -> EvolveState:
+    def body(s):
+        NULL_TRACER.count("evolve.loop_traces")
+        return generation_step(s, spec, cfg, eval_fn)
+
+    return jax.lax.while_loop(lambda s: not_terminated(s, cfg), body, state)
+
+
 def evolve(
     key: jax.Array, spec: CircuitSpec, cfg: EvolveConfig, eval_fn: BatchEvalFn,
     seed_genome: "Genome | None" = None,
 ) -> EvolveState:
     """Run to termination (lax.while_loop — early exit, no history).
 
-    Spans ``evolve.init`` (the eager initial state) and ``evolve.loop``
-    (tracing, lowering, compile or cache load, and enqueue of the loop),
-    and the count ``evolve.loop_traces`` (once per trace of the body),
-    reach a JAX profiler capture (repro.observability.trace)."""
-
-    def body(s):
-        NULL_TRACER.count("evolve.loop_traces")
-        return generation_step(s, spec, cfg, eval_fn)
-
+    Uncached: ``eval_fn`` closes over the caller's data, so every call
+    traces, lowers and loads the loop anew (`evolve_packed` runs the same
+    search through cached programs).  Spans ``evolve.init`` (the eager
+    initial state) and ``evolve.loop`` (tracing, lowering, compile or
+    cache load, and enqueue of the loop), and the count
+    ``evolve.loop_traces`` (once per trace of the body), reach a JAX
+    profiler capture (repro.observability.trace)."""
     with NULL_TRACER.span("evolve.init"):
         state = init_state(key, spec, eval_fn, seed_genome=seed_genome)
     with NULL_TRACER.span("evolve.loop"):
-        return jax.lax.while_loop(
-            lambda s: not_terminated(s, cfg), body, state)
+        return _run_loop(state, spec, cfg, eval_fn)
 
 
 def evolve_with_history(
@@ -195,6 +211,43 @@ def evolve_with_history(
     return final, hist
 
 
+# Modules whose functions the search programs reach through module
+# attributes while they are traced.
+_TRACED_MODULES = (
+    sys.modules[__name__], F, genome_module, mutate_module,
+    runtime_backends, circuit_eval, ref_kernels,
+)
+
+
+def _traced_functions() -> tuple:
+    """The functions of `_TRACED_MODULES` as their attributes hold them
+    now: part of the search programs' cache key, so that a function
+    replaced since a program was traced (a patch, a reload) keys a new
+    trace instead of reusing a program built from the old one."""
+    return tuple(v for m in _TRACED_MODULES for v in vars(m).values()
+                 if isinstance(getattr(v, "__wrapped__", v), types.FunctionType))
+
+
+# The cached search programs.  Static: spec, config, resolved backend and
+# `_traced_functions()`; the data, masks, key and state are arguments, so
+# one program serves every search of its shapes in the process.
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _init_program(spec, cfg, backend, functions, key, data, mask_train,
+                  mask_val, seed_genome):
+    del functions  # cache key only
+    eval_fn = make_eval_fn(spec, data, mask_train, mask_val, backend)
+    return init_state(key, spec, eval_fn, seed_genome=seed_genome)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _loop_program(spec, cfg, backend, functions, state, data, mask_train,
+                  mask_val):
+    del functions  # cache key only
+    eval_fn = make_eval_fn(spec, data, mask_train, mask_val, backend)
+    return _run_loop(state, spec, cfg, eval_fn)
+
+
 def evolve_packed(
     key: jax.Array,
     spec: CircuitSpec,
@@ -204,7 +257,23 @@ def evolve_packed(
     mask_val: jax.Array,
     seed_genome: "Genome | None" = None,
 ) -> EvolveState:
-    """Convenience: evolve directly on a PackedDataset.  ``seed_genome``
-    warm-starts the search from an existing circuit (online refit)."""
-    eval_fn = make_eval_fn(spec, data, mask_train, mask_val, cfg.backend)
-    return evolve(key, spec, cfg, eval_fn, seed_genome=seed_genome)
+    """`evolve` directly on a PackedDataset, through two cached jitted
+    programs: the initial state, and the loop.  Each is traced and
+    compiled once per distinct key — array shapes and dtypes, ``spec``,
+    ``cfg``, the resolved backend, whether ``seed_genome`` is given, and
+    the functions of the modules it traces — and reused by every later
+    search in the process; a new shape, spec, config or backend (or a
+    replaced function) traces again.  Same random stream, arithmetic and
+    termination as `evolve`.  ``seed_genome`` warm-starts the search
+    from an existing circuit (online refit).
+
+    Spans ``evolve.init`` and ``evolve.loop`` time the two programs'
+    dispatch (their trace and compile too, where the key is new); the
+    count ``evolve.loop_traces`` counts real traces of the loop body."""
+    static = (spec, cfg, runtime.resolve_backend(cfg.backend),
+              _traced_functions())
+    with NULL_TRACER.span("evolve.init"):
+        state = _init_program(*static, key, data, mask_train, mask_val,
+                              seed_genome)
+    with NULL_TRACER.span("evolve.loop"):
+        return _loop_program(*static, state, data, mask_train, mask_val)
