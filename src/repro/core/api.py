@@ -372,13 +372,17 @@ class AutoTinyClassifier:
         self.records_ = []
         for ei, ecfg in enumerate(self.encodings):
             with NULL_TRACER.span("fit.encode"):
-                enc = E.fit_encoder(x, ecfg)
-                bits = E.encode(enc, x)
-                data = E.pack_dataset(bits, y, self.n_classes_, n_out)
+                with NULL_TRACER.span("fit.encode.edges"):
+                    enc = E.fit_encoder(x, ecfg)
+                with NULL_TRACER.span("fit.encode.bits"):
+                    bits = E.encode(enc, x)
+                with NULL_TRACER.span("fit.encode.pack"):
+                    data = E.pack_dataset(bits, y, self.n_classes_, n_out)
                 w = data.x_words.shape[1]
-                mtr, mva = E.split_masks(
-                    x.shape[0], w, self.val_fraction, seed=self.seed + ei
-                )
+                with NULL_TRACER.span("fit.encode.split"):
+                    mtr, mva = E.split_masks(
+                        x.shape[0], w, self.val_fraction, seed=self.seed + ei
+                    )
             spec = CircuitSpec(
                 n_inputs=bits.shape[1], n_nodes=self.n_gates,
                 n_outputs=n_out, fn_set=self.fn_set,
@@ -394,13 +398,13 @@ class AutoTinyClassifier:
                 )
             self.records_.append(rec)
             if best is None or rec.val_fitness > best[0]:
-                # per-bit activation frequency of the encoded training
-                # data: the reference snapshot online drift detection
-                # compares live traffic against (bundle v2 `ref_stats`)
-                best = (rec.val_fitness, spec, final.best, enc,
-                        bits.mean(axis=0).astype(np.float32))
-        (_, self.spec_, self.genome_, self.encoder_,
-         self.ref_stats_) = best
+                best = (rec.val_fitness, spec, final.best, enc, bits)
+        (_, self.spec_, self.genome_, self.encoder_, best_bits) = best
+        # per-bit activation frequency of the chosen encoding's training
+        # data: the reference snapshot online drift detection compares
+        # live traffic against (bundle v2 `ref_stats`)
+        with NULL_TRACER.span("fit.ref_stats"):
+            self.ref_stats_ = best_bits.mean(axis=0).astype(np.float32)
         return self
 
     # ------------------------------------------------------------------

@@ -9,7 +9,11 @@ from repro.core.encoding import EncodingConfig
 from repro.observability.trace import captured, reset_captured
 from tests.test_observability import host_event_names
 
-LEAVES = ("fit.encode", "evolve.init", "evolve.loop", "fit.readback")
+LEAVES = ("fit.encode", "evolve.init", "evolve.loop", "fit.readback",
+          "fit.ref_stats")
+# the steps of `fit.encode`, each a span inside it
+ENCODE_STEPS = ("fit.encode.edges", "fit.encode.bits", "fit.encode.pack",
+                "fit.encode.split")
 
 
 def small_fit():
@@ -40,7 +44,9 @@ def capture(tmp_path_factory):
 
 @pytest.mark.parametrize("name,count", [
     ("fit", 1), ("fit.encode", 2), ("evolve.init", 2), ("evolve.loop", 2),
-    ("fit.readback", 2), ("evolve.loop_traces", 1)])
+    ("fit.readback", 2), ("evolve.loop_traces", 1),
+    ("fit.encode.edges", 2), ("fit.encode.bits", 2), ("fit.encode.pack", 2),
+    ("fit.encode.split", 2), ("fit.ref_stats", 1)])
 def test_fit_under_a_capture_counts_each_span(capture, name, count):
     got, _ = capture
     assert got[name]["count"] == count
@@ -48,10 +54,17 @@ def test_fit_under_a_capture_counts_each_span(capture, name, count):
 
 def test_fit_spans_nest_inside_fit_and_reach_the_host_plane(capture):
     got, trace_dir = capture
-    assert set(got) == {"fit", "evolve.loop_traces", *LEAVES}
-    assert all(got[name]["seconds"] > 0 for name in ("fit", *LEAVES))
+    assert set(got) == {"fit", "evolve.loop_traces", *LEAVES, *ENCODE_STEPS}
+    assert all(got[name]["seconds"] > 0
+               for name in ("fit", *LEAVES, *ENCODE_STEPS))
     assert sum(got[name]["seconds"] for name in LEAVES) <= got["fit"]["seconds"]
-    assert {"fit", *LEAVES} <= host_event_names(trace_dir)
+    assert {"fit", *LEAVES, *ENCODE_STEPS} <= host_event_names(trace_dir)
+
+
+def test_encode_steps_nest_inside_fit_encode(capture):
+    got, _ = capture
+    steps = sum(got[name]["seconds"] for name in ENCODE_STEPS)
+    assert steps <= got["fit.encode"]["seconds"]
 
 
 def test_fit_after_stop_trace_records_nothing(tmp_path):

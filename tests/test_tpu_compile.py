@@ -60,7 +60,8 @@ def _assert_kernel(compiled):
 @pytest.mark.parametrize("n_inputs,rows", [
     (29 * 4, 98_050),     # higgs, quantize-4
     (1_637 * 4, 5_418),   # christine, quantize-4: the widest VMEM table
-], ids=["higgs", "christine-q4"])
+    (10 * 4, 1_496_391),  # clickpred, quantize-4: the longest grid
+], ids=["higgs", "christine-q4", "clickpred-q4"])
 def test_search_kernel_compiles(chip, n_inputs, rows):
     lam, n_out = 4, 1
     args = _shapes(
