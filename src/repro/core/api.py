@@ -7,6 +7,9 @@ fit(X, y):
   3. keep the circuit with the best validation fitness across encodings
      (paper §5.2: "experiments report the best-achieved accuracy across the
      available encoding strategies with two and four bits per input").
+  Every encoding's search is dispatched before any is read back, so the
+  host encodes the next candidate while the device runs the last one's
+  search; the results are identical to running them one at a time.
 
 predict / balanced_score: evaluate the evolved circuit.
 to_verilog / to_c / hardware_report: the ASIC/FPGA toolflow (§4).
@@ -356,6 +359,12 @@ class AutoTinyClassifier:
 
     # ------------------------------------------------------------------
     def fit(self, x: np.ndarray, y: np.ndarray, n_classes: int | None = None):
+        """Search a circuit for each encoding and keep the one with the
+        best validation fitness (the first of equals).  Every encoding's
+        search is dispatched before any is read back, so encoding runs on
+        the host while earlier searches run on the device; the records,
+        circuit, encoder and ``ref_stats_`` are identical to running the
+        searches one at a time."""
         # spans cost one branch unless a JAX profiler capture records
         # (repro.observability.trace)
         with NULL_TRACER.span("fit"):
@@ -368,9 +377,13 @@ class AutoTinyClassifier:
         n_out = self.n_out_bits or max(
             1, int(np.ceil(np.log2(max(self.n_classes_, 2))))
         )
-        best = None
-        self.records_ = []
+        # every search is dispatched before any is read back: encoding
+        # i + 1 runs on the host while search i runs on the device, which
+        # runs the searches in dispatch order
+        searches = []
         for ei, ecfg in enumerate(self.encodings):
+            if searches:
+                NULL_TRACER.count("fit.encode_overlapped")
             with NULL_TRACER.span("fit.encode"):
                 with NULL_TRACER.span("fit.encode.edges"):
                     enc = E.fit_encoder(x, ecfg)
@@ -389,6 +402,12 @@ class AutoTinyClassifier:
             )
             key = jax.random.key(self.seed * 1000 + ei)
             final: EvolveState = evolve_packed(key, spec, self.cfg, data, mtr, mva)
+            searches.append((ecfg, spec, enc, bits, final))
+        best = None
+        self.records_ = []
+        while searches:
+            # popped, so an encoding's bits go once selection passes them
+            ecfg, spec, enc, bits, final = searches.pop(0)
             with NULL_TRACER.span("fit.readback"):  # waits for the loop
                 rec = FitRecord(
                     encoding=ecfg,

@@ -46,7 +46,8 @@ def capture(tmp_path_factory):
     ("fit", 1), ("fit.encode", 2), ("evolve.init", 2), ("evolve.loop", 2),
     ("fit.readback", 2), ("evolve.loop_traces", 1),
     ("fit.encode.edges", 2), ("fit.encode.bits", 2), ("fit.encode.pack", 2),
-    ("fit.encode.split", 2), ("fit.ref_stats", 1)])
+    ("fit.encode.split", 2), ("fit.ref_stats", 1),
+    ("fit.encode_overlapped", 1)])
 def test_fit_under_a_capture_counts_each_span(capture, name, count):
     got, _ = capture
     assert got[name]["count"] == count
@@ -54,7 +55,8 @@ def test_fit_under_a_capture_counts_each_span(capture, name, count):
 
 def test_fit_spans_nest_inside_fit_and_reach_the_host_plane(capture):
     got, trace_dir = capture
-    assert set(got) == {"fit", "evolve.loop_traces", *LEAVES, *ENCODE_STEPS}
+    assert set(got) == {"fit", "evolve.loop_traces", "fit.encode_overlapped",
+                        *LEAVES, *ENCODE_STEPS}
     assert all(got[name]["seconds"] > 0
                for name in ("fit", *LEAVES, *ENCODE_STEPS))
     assert sum(got[name]["seconds"] for name in LEAVES) <= got["fit"]["seconds"]
